@@ -147,4 +147,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.backend import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}")
     main()

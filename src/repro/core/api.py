@@ -11,8 +11,8 @@ compiled kernel.  Host code reaches it through
 Targets:
   ``vector``  — work-items on lanes, if-converted divergence (SIMD mapping)
   ``loop``    — serial work-item loops ('basic' driver analogue)
-  ``pallas``  — vector mapping wrapped in a ``pl.pallas_call`` (TPU path,
-                validated with interpret=True on CPU)
+  ``pallas``  — vector mapping wrapped in a ``pl.pallas_call`` (Mosaic on
+                the TPU, interpret mode only on the CPU)
   ``auto``    — target chosen per kernel shape by the autotuner
                 (:mod:`repro.core.autotune`)
 
@@ -80,25 +80,21 @@ class CompiledKernel:
                  scalars: Optional[Dict[str, object]] = None,
                  jit: bool = True,
                  group_range: Optional[Sequence[int]] = None
-                 ) -> Dict[str, np.ndarray]:
+                 ) -> Dict[str, jax.Array]:
         """Launch over ``global_size``.  ``group_range=(lo, hi)`` executes
         only that contiguous range of linearized work-groups of the full
         NDRange (the multi-device co-execution unit, runtime/scheduler.py);
         group-id decoding is unchanged, so results over the sub-range are
-        identical to the same groups of a full launch."""
+        identical to the same groups of a full launch.  The outputs are
+        device arrays on the device that holds the input buffers
+        (:meth:`repro.runtime.platform.Device.launch` places them)."""
         gsz = tuple(global_size)
         grange = None if group_range is None \
             else (int(group_range[0]), int(group_range[1]))
         scalars = scalars or {}
-        # the pallas target needs scalar args as jaxpr literals (pallas
-        # rejects captured device constants), so launch it un-jitted —
-        # pallas_call compiles the kernel itself
-        if type(self.prog).__name__ == "PallasWGProgram":
-            jit = False
-        if not jit:
-            out = self.prog.run_ndrange(buffers, scalars, gsz,
-                                        group_range=grange)
-            return {k: np.asarray(v) for k, v in out.items()}
+        if not (jit and self.prog.jittable):
+            return self.prog.run_ndrange(buffers, scalars, gsz,
+                                         group_range=grange)
         key = (gsz, grange, tuple(sorted((k, v.shape, str(v.dtype))
                                          for k, v in buffers.items())))
         with self._jit_lock:
@@ -109,8 +105,7 @@ class CompiledKernel:
                                                  group_range=grange)
                 fn = jax.jit(launch)
                 self._jit_cache[key] = fn
-        out = fn(buffers, {k: np.asarray(v) for k, v in scalars.items()})
-        return {k: np.asarray(v) for k, v in out.items()}
+        return fn(buffers, {k: np.asarray(v) for k, v in scalars.items()})
 
     # compiler introspection (used by tests/benchmarks)
     @property

@@ -31,6 +31,8 @@ import time
 import warnings
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
+import jax
+
 from .cache import CacheKey, ir_hash
 from .errors import BuildError
 from .ir import Function
@@ -379,13 +381,15 @@ class AutotunedKernel:
             try:
                 k = self.kernel_for(target)
                 for _ in range(self.warmup):
-                    outputs[target] = k(buffers, gsz, scalars, jit=jit,
-                                        group_range=group_range)
+                    outputs[target] = jax.block_until_ready(
+                        k(buffers, gsz, scalars, jit=jit,
+                          group_range=group_range))
                 best = float("inf")
                 for _ in range(self.repeats):
                     t0 = time.perf_counter()
-                    outputs[target] = k(buffers, gsz, scalars, jit=jit,
-                                        group_range=group_range)
+                    outputs[target] = jax.block_until_ready(
+                        k(buffers, gsz, scalars, jit=jit,
+                          group_range=group_range))
                     best = min(best, time.perf_counter() - t0)
                 timings[target] = best * 1e6
             except Exception as e:

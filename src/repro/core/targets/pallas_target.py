@@ -13,8 +13,11 @@ arbitrary addresses; the TPU grid is sequential, so aliased output refs give
 every work-group a consistent running view — legal under OpenCL's
 no-inter-group-dependency contract.
 
-Validated with ``interpret=True`` on CPU; on real TPUs the same code lowers
-to Mosaic.
+The kernel runs in interpret mode only on the CPU
+(:func:`repro.backend.pallas_interpret`).  On the TPU it compiles through
+Mosaic, and a kernel Mosaic refuses raises the typed
+:class:`~repro.core.errors.BuildError` with the compiler's message in its
+build log; it never falls back to interpret mode.
 """
 
 from __future__ import annotations
@@ -26,12 +29,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ...backend import pallas_interpret
 from .. import ir
+from ..errors import BuildError
 from .vector import WGProgram
 
 
 class PallasWGProgram(WGProgram):
-    interpret = True  # CPU container; flip to False on real TPUs
+    # scalar args must embed as jaxpr literals (pallas_call rejects
+    # captured device constants), so the launch is not wrapped in jit:
+    # run_ndrange compiles the pallas_call itself
+    jittable = False
+
+    @property
+    def interpret(self) -> bool:
+        """Interpret mode: on only when the default backend is the CPU."""
+        return pallas_interpret()
 
     def run_ndrange(self, buffers: Dict[str, np.ndarray],
                     scalars: Optional[Dict[str, object]],
@@ -90,5 +103,14 @@ class PallasWGProgram(WGProgram):
             input_output_aliases={i: i for i in range(len(names))},
             interpret=self.interpret,
         )
-        out = call(*[bufs[n] for n in names])
-        return dict(zip(names, out))
+        args = [bufs[n] for n in names]
+        try:
+            compiled = jax.jit(call).lower(*args).compile()
+        except Exception as e:
+            # the backend compiler (Mosaic on the TPU) refused the kernel
+            name = self.wg.fn.name
+            raise BuildError(
+                f"pallas target: the backend compiler refused kernel "
+                f"{name!r} ({type(e).__name__})",
+                build_log=f"{type(e).__name__}: {e}") from e
+        return dict(zip(names, compiled(*args)))

@@ -474,6 +474,11 @@ class WGProgram:
     passing a raw :class:`Function` is a compatibility path that builds the
     plan through the pass manager first."""
 
+    #: whether a launch may be wrapped in ``jax.jit`` (scalars become
+    #: traced arguments); a target whose kernel needs them as constants
+    #: says False and is launched as it is
+    jittable = True
+
     def __init__(self, plan: "WorkGroupPlan | Function",
                  local_size: Sequence[int],
                  horizontal: bool = True, merge_uniform: bool = True,

@@ -41,15 +41,11 @@ def hierarchical_grad_reduce(grad_fn, mesh: Mesh, batch_spec):
     """Wrap a per-shard grad function so its output grads are reduced
     hierarchically.  grad_fn(params, batch) -> grads (unreduced, local).
     Params replicated; batch sharded by batch_spec along ('pod','data')."""
-    from jax.experimental.shard_map import shard_map
-
-    axes = [a for a in ("pod", "data") if a in mesh.axis_names]
-
     def inner(params, batch):
         grads = grad_fn(params, batch)
         return hierarchical_psum(grads, mesh)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(P(), batch_spec),
-                     out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(P(), batch_spec),
+                         out_specs=P(),
+                         check_vma=False)

@@ -30,7 +30,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, use_vml: bool):
                                              "interpret"))
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
             block_rows: int = 128, use_vml: bool = True,
-            interpret: bool = True) -> jnp.ndarray:
+            *, interpret: bool) -> jnp.ndarray:
     """x: (..., d); w: (d,).  Rows are tiled over the grid."""
     orig_shape = x.shape
     d = x.shape[-1]

@@ -73,7 +73,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
              B: jnp.ndarray, C: jnp.ndarray, chunk: int = 64,
-             interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             *, interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Shapes as in :func:`repro.kernels.ref.ssd_scan`.
 
     Returns (y, final_state) with y: (b, s, h, p), state: (b, h, p, n).

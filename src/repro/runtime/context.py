@@ -256,7 +256,7 @@ class Context:
         device = self._check_device(device, "launch")
         buffers, scalars = kernel.launch_args(accept=("host",))
         binary = kernel.bind(device, local_size, target=target)
-        out = binary(buffers, tuple(global_size), scalars)
+        out = device.launch(binary, buffers, tuple(global_size), scalars)
         return {k: np.asarray(v) for k, v in out.items()}
 
     # -- introspection ------------------------------------------------------------

@@ -61,6 +61,7 @@ class Device:
 
     def __init__(self, info: DeviceInfo, jax_device=None):
         self.info = info
+        # the JAX device every launch of this device runs on
         self.jax_device = jax_device or jax.devices()[0]
         # Bufalloc manages the device buffer address space (the paper's
         # "host keeps book of all buffer allocations for a known region")
@@ -100,6 +101,14 @@ class Device:
             "Kernel object (docs/host_api.md)",
             DeprecationWarning, stacklevel=2)
         return self.compile(build, local_size, **opts)
+
+    def launch(self, binary, buffers, global_size, scalars=None,
+               group_range=None):
+        """Run a compiled kernel (:meth:`compile`) with its buffers
+        placed on :attr:`jax_device`; returns the outputs, which stay
+        on that device until the caller reads them."""
+        bufs = jax.device_put(dict(buffers), self.jax_device)
+        return binary(bufs, global_size, scalars, group_range=group_range)
 
     def cache_stats(self) -> Dict[str, int]:
         """Compilation-cache counters for this device (hits, misses,
@@ -356,32 +365,40 @@ class Buffer:
             self.chunk = None
 
 
+def global_mem_size(jax_device) -> int:
+    """CL_DEVICE_GLOBAL_MEM_SIZE of a JAX device: the allocator limit
+    the backend reports (``memory_stats()["bytes_limit"]``), or 1 GiB
+    where it reports none (the CPU)."""
+    stats = jax_device.memory_stats() or {}
+    return int(stats.get("bytes_limit", 1 << 30))
+
+
 class Platform:
     """clGetPlatformIDs analogue: enumerates devices for the process."""
 
     def __init__(self):
         self.devices: List[Device] = []
-        ndev = len(jax.devices())
-        for i, d in enumerate(jax.devices()):
-            self.devices.append(Device(DeviceInfo(
-                name=f"repro-{d.platform}-{i}", driver="vector",
-                global_mem_size=1 << 30, local_mem_size=1 << 20,
-                max_work_group_size=1024, compute_units=ndev), d))
+        jdevs = jax.devices()
+        for i, d in enumerate(jdevs):
+            self.devices.append(self._device(
+                f"repro-{d.platform}-{i}", "vector", d,
+                compute_units=len(jdevs)))
         # a 'basic' serial device is always available (pocl's reference)
-        self.devices.append(Device(DeviceInfo(
-            name="repro-basic", driver="basic",
-            global_mem_size=1 << 30, local_mem_size=1 << 20,
-            max_work_group_size=1024, compute_units=1)))
-        self.devices.append(Device(DeviceInfo(
-            name="repro-pallas", driver="pallas",
-            global_mem_size=1 << 30, local_mem_size=1 << 20,
-            max_work_group_size=1024, compute_units=1)))
+        self.devices.append(self._device("repro-basic", "basic", jdevs[0]))
+        self.devices.append(self._device("repro-pallas", "pallas",
+                                         jdevs[0]))
         # an autotuned device: the target is picked per kernel shape by
         # measurement (the per-platform mapping choice of Rupp & Weinbub)
-        self.devices.append(Device(DeviceInfo(
-            name="repro-auto", driver="auto",
-            global_mem_size=1 << 30, local_mem_size=1 << 20,
-            max_work_group_size=1024, compute_units=1)))
+        self.devices.append(self._device("repro-auto", "auto", jdevs[0]))
+
+    @staticmethod
+    def _device(name: str, driver: str, jax_device,
+                compute_units: int = 1) -> Device:
+        return Device(DeviceInfo(
+            name=name, driver=driver,
+            global_mem_size=global_mem_size(jax_device),
+            local_mem_size=1 << 20, max_work_group_size=1024,
+            compute_units=compute_units), jax_device)
 
     def get_devices(self, driver: Optional[str] = None) -> List[Device]:
         """clGetDeviceIDs: all devices, or those of one driver kind."""
@@ -394,15 +411,13 @@ class Platform:
         co-execution (the analogue of EngineCL's device set over one
         platform).  Each device owns its own allocator and compilation
         cache; the multi-device scheduler (runtime/scheduler.py) fans
-        sub-ranges of one NDRange out across them.  The devices are
-        appended to :attr:`devices` so ``cache_stats`` sees them."""
-        out = []
-        for i in range(n):
-            d = Device(DeviceInfo(
-                name=f"repro-co-{driver}-{i}", driver=driver,
-                global_mem_size=1 << 30, local_mem_size=1 << 20,
-                max_work_group_size=1024, compute_units=1))
-            out.append(d)
+        sub-ranges of one NDRange out across them.  Device ``i`` binds
+        JAX device ``i % len(jax.devices())``: distinct chips while there
+        are enough, shared ones after.  The devices are appended to
+        :attr:`devices` so ``cache_stats`` sees them."""
+        jdevs = jax.devices()
+        out = [self._device(f"repro-co-{driver}-{i}", driver,
+                            jdevs[i % len(jdevs)]) for i in range(n)]
         self.devices.extend(out)
         return out
 

@@ -493,16 +493,13 @@ class CommandQueue:
         shared_root = {k for k, b in buffers.items()
                        if roots[id(b.root)] > 1}
         snaps = {k: np.array(arrs[k], copy=True) for k in shared_root}
-        if group_range is None:
-            out = kernel(arrs, global_size, scalars)
-        else:
-            out = kernel(arrs, global_size, scalars,
-                         group_range=group_range)
+        out = self.device.launch(kernel, arrs, global_size, scalars,
+                                 group_range=group_range)
         for k, b in buffers.items():
-            if k in shared_root and \
-                    np.array_equal(np.asarray(out[k]), snaps[k]):
+            res = np.asarray(out[k])    # host payload mirror
+            if k in shared_root and np.array_equal(res, snaps[k]):
                 continue            # observably unwritten aliased view
-            b.data = out[k]
+            b.data = res
             # conservative write publication: without kernel-side access
             # metadata every written-back buffer counts as written
             # (OpenCL makes the same assumption for cl_mem without

@@ -392,6 +392,11 @@ class ThroughputModel:
             known = [self._rate[d] for d in devices if d in self._rate]
             fill = (sum(known) / len(known)) if known else 1.0
             raw = [self._rate.get(d, fill) for d in devices]
+        # relative to the fastest device, floored: a sum of huge finite
+        # rates would overflow to inf, and a ratio of extreme ones would
+        # underflow to a zero weight
+        top = max(raw)
+        raw = [max(r / top, 1e-12) for r in raw]
         total = sum(raw)
         return [r / total for r in raw]
 
@@ -733,8 +738,9 @@ class CoExecutor:
             # safety net if a transfer was skipped as clean)
             arrs = {nm: sb.device_array(device)
                     for nm, sb in shared.items()}
-            out = kernels[device](arrs, global_size, scalars,
-                                  group_range=(lo, hi))
+            out = {nm: np.asarray(v) for nm, v in device.launch(
+                kernels[device], arrs, global_size, scalars,
+                group_range=(lo, hi)).items()}
             for nm, sb in shared.items():
                 sb.store_local(device, out[nm])
             with plock:

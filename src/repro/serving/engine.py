@@ -138,8 +138,9 @@ class ServingEngine:
         Worker threads of the dispatch queue; >=2 lets refill prefills
         overlap the decode command.
     device / context:
-        Runtime placement, exactly as before: the dispatch queue and the
-        KV page pool come from the host
+        Runtime placement: the default executor's params and caches live
+        on ``device.jax_device``, and the dispatch queue and the KV page
+        pool come from the host
         :class:`~repro.runtime.context.Context` (engines sharing a
         context share KV free lists); a foreign device falls back to
         engine-owned resources.
@@ -175,19 +176,6 @@ class ServingEngine:
         self.aux = aux_inputs or {}
         self.scheduler = scheduler
 
-        if executor is None:
-            from .executor import JaxExecutor
-            executor = JaxExecutor(cfg, params, rules, batch_slots,
-                                   max_seq, aux_inputs=aux_inputs,
-                                   prefill_bucket=prefill_bucket)
-        if executor.batch_slots != batch_slots or \
-                executor.max_seq != max_seq:
-            raise InvalidArgError(
-                f"executor shape ({executor.batch_slots}, "
-                f"{executor.max_seq}) does not match engine "
-                f"({batch_slots}, {max_seq})")
-        self._exec = executor
-
         # runtime resources from the host Context (docs/host_api.md);
         # a caller-supplied device outside the context's platform falls
         # back to engine-owned queue + pool, as before
@@ -197,6 +185,20 @@ class ServingEngine:
         self.context = context
         if device is None:
             device = context.devices[0]
+
+        if executor is None:
+            from .executor import JaxExecutor
+            executor = JaxExecutor(cfg, params, rules, batch_slots,
+                                   max_seq, aux_inputs=aux_inputs,
+                                   prefill_bucket=prefill_bucket,
+                                   device=device.jax_device)
+        if executor.batch_slots != batch_slots or \
+                executor.max_seq != max_seq:
+            raise InvalidArgError(
+                f"executor shape ({executor.batch_slots}, "
+                f"{executor.max_seq}) does not match engine "
+                f"({batch_slots}, {max_seq})")
+        self._exec = executor
         try:
             self._queue = context.create_queue(
                 device, out_of_order=True, workers=max(1, dag_workers),

@@ -65,8 +65,62 @@ class BatchExecutor:
 # production executor over the jitted model
 # ---------------------------------------------------------------------------
 
+def step_functions(cfg, rules, aux: Dict[str, np.ndarray]):
+    """The jitted ``(prefill, insert, decode)`` steps of
+    :class:`JaxExecutor` over ``repro.models.forward``; each donates the
+    cache it updates.  Module-level so a compile rehearsal can lower them
+    for a described chip without placing any weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import cache_logical_axes, forward
+
+    axes = cache_logical_axes(cfg)
+
+    def batch_axis(key: str) -> int:
+        ax = axes.get(key)
+        if ax and "batch" in ax:
+            return ax.index("batch")
+        return 0          # "len" and any unannotated leaf: axis 0
+
+    def prefill_fn(params, toks, caches, last_idx, true_len, slot):
+        aux_row = {k: jax.lax.dynamic_slice_in_dim(jnp.asarray(v), slot, 1,
+                                                   axis=0)
+                   for k, v in aux.items()}
+        logits, _, caches = forward(params, toks, cfg, rules,
+                                    aux_inputs=aux_row, caches=caches,
+                                    mode="prefill")
+        tok = jnp.argmax(logits[0, last_idx]).astype(jnp.int32)
+        caches = dict(caches)
+        caches["len"] = jnp.full_like(caches["len"], true_len)
+        return tok, caches
+
+    def insert_fn(state, frag, slot):
+        out = {}
+        for key, leaf in state.items():
+            start = [0] * leaf.ndim
+            start[batch_axis(key)] = slot
+            out[key] = jax.lax.dynamic_update_slice(
+                leaf, frag[key].astype(leaf.dtype), tuple(start))
+        return out
+
+    def decode_fn(params, toks, caches, occupied):
+        logits, _, caches = forward(params, toks, cfg, rules,
+                                    aux_inputs=aux, caches=caches,
+                                    mode="decode")
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        caches = dict(caches)
+        caches["len"] = jnp.where(occupied, caches["len"], 0)
+        return tok, caches
+
+    return (jax.jit(prefill_fn, donate_argnums=(2,)),
+            jax.jit(insert_fn, donate_argnums=(0,)),
+            jax.jit(decode_fn, donate_argnums=(2,)))
+
+
 class JaxExecutor(BatchExecutor):
-    """Jitted prefill / insert / decode over ``repro.models.forward``.
+    """Jitted prefill / insert / decode over ``repro.models.forward``
+    (:func:`step_functions`), placed on one JAX device.
 
     Three jitted functions, each compiled once per shape:
 
@@ -81,71 +135,43 @@ class JaxExecutor(BatchExecutor):
       (from :func:`repro.models.cache_logical_axes`).
     * decode: one token for the whole batch; empty slots are masked —
       their cache length is pinned to 0 so they never grow or attend.
+
+    ``device`` (a JAX device, default ``jax.devices()[0]``) holds the
+    params and every cache, so every step runs there.
     """
 
     def __init__(self, cfg, params, rules, batch_slots: int, max_seq: int,
-                 aux_inputs: Optional[Dict] = None, prefill_bucket: int = 8):
+                 aux_inputs: Optional[Dict] = None, prefill_bucket: int = 8,
+                 device=None):
         import jax
-        import jax.numpy as jnp
 
-        from repro.models import cache_logical_axes, forward, init_caches
+        from repro.models import init_caches
 
-        self.cfg, self.params, self.rules = cfg, params, rules
+        self.device = device if device is not None else jax.devices()[0]
+        self.cfg, self.rules = cfg, rules
+        self.params = jax.device_put(params, self.device)
         self.batch_slots, self.max_seq = batch_slots, max_seq
         self.aux = {k: np.asarray(v) for k, v in (aux_inputs or {}).items()}
         self.prefill_bucket = max(1, prefill_bucket)
         self._init_caches = init_caches
-        self._axes = cache_logical_axes(cfg)
-        self._jnp = jnp
-
-        def _batch_axis(key: str) -> int:
-            ax = self._axes.get(key)
-            if ax and "batch" in ax:
-                return ax.index("batch")
-            return 0          # "len" and any unannotated leaf: axis 0
-
-        self._batch_axis = _batch_axis
-
-        def prefill_fn(params, toks, caches, last_idx, true_len, slot):
-            aux = {k: jax.lax.dynamic_slice_in_dim(jnp.asarray(v), slot, 1,
-                                                   axis=0)
-                   for k, v in self.aux.items()}
-            logits, _, caches = forward(params, toks, cfg, rules,
-                                        aux_inputs=aux, caches=caches,
-                                        mode="prefill")
-            tok = jnp.argmax(logits[0, last_idx]).astype(jnp.int32)
-            caches = dict(caches)
-            caches["len"] = jnp.full_like(caches["len"], true_len)
-            return tok, caches
-
-        def insert_fn(state, frag, slot):
-            out = {}
-            for key, leaf in state.items():
-                start = [0] * leaf.ndim
-                start[_batch_axis(key)] = slot
-                out[key] = jax.lax.dynamic_update_slice(
-                    leaf, frag[key].astype(leaf.dtype), tuple(start))
-            return out
-
-        def decode_fn(params, toks, caches, occupied):
-            logits, _, caches = forward(params, toks, cfg, rules,
-                                        aux_inputs=self.aux, caches=caches,
-                                        mode="decode")
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            caches = dict(caches)
-            caches["len"] = jnp.where(occupied, caches["len"], 0)
-            return tok, caches
-
-        self._prefill = jax.jit(prefill_fn, donate_argnums=(2,))
-        self._insert = jax.jit(insert_fn, donate_argnums=(0,))
-        self._decode = jax.jit(decode_fn, donate_argnums=(2,))
-        self._prefill_shapes: set = set()
+        self._jax = jax
+        self._prefill, self._insert, self._decode = step_functions(
+            cfg, rules, self.aux)
         self._calls = {"prefill": 0, "decode": 0, "insert": 0}
         self._lock = threading.Lock()
 
+    def _caches(self, batch: int):
+        """Zeroed caches for ``batch`` rows, created on the device."""
+        with self._jax.default_device(self.device):
+            caches = self._init_caches(self.cfg, batch, self.max_seq)
+        return self._jax.device_put(caches, self.device)
+
+    def _put(self, x):
+        return self._jax.device_put(x, self.device)
+
     # -- interface -------------------------------------------------------------
     def init_state(self):
-        return self._init_caches(self.cfg, self.batch_slots, self.max_seq)
+        return self._caches(self.batch_slots)
 
     def bucket(self, prompt_len: int) -> int:
         """Padded prefill length for a prompt (pow2, floored, capped)."""
@@ -154,18 +180,15 @@ class JaxExecutor(BatchExecutor):
         return min(b, self.max_seq)
 
     def prefill(self, prompt: np.ndarray, slot: int):
-        jnp = self._jnp
         plen = int(len(prompt))
         padded = self.bucket(plen)
         toks = np.zeros((1, padded), np.int32)
         toks[0, :plen] = prompt
         with self._lock:
             self._calls["prefill"] += 1
-            self._prefill_shapes.add(padded)
-        caches = self._init_caches(self.cfg, 1, self.max_seq)
-        tok, frag = self._prefill(self.params, jnp.asarray(toks), caches,
-                                  np.int32(plen - 1), np.int32(plen),
-                                  np.int32(slot))
+        tok, frag = self._prefill(self.params, self._put(toks),
+                                  self._caches(1), np.int32(plen - 1),
+                                  np.int32(plen), np.int32(slot))
         return frag, int(tok)
 
     def insert(self, state, fragment, slot: int):
@@ -174,12 +197,11 @@ class JaxExecutor(BatchExecutor):
         return self._insert(state, fragment, np.int32(slot))
 
     def decode(self, state, tokens: np.ndarray, occupied: np.ndarray):
-        jnp = self._jnp
         with self._lock:
             self._calls["decode"] += 1
-        tok, state = self._decode(self.params,
-                                  jnp.asarray(tokens, jnp.int32)[:, None],
-                                  state, jnp.asarray(occupied))
+        tok, state = self._decode(
+            self.params, self._put(np.asarray(tokens, np.int32)[:, None]),
+            state, self._put(np.asarray(occupied)))
         return state, np.asarray(tok)
 
     def cache_bytes(self, batch: int, seq: int) -> int:
@@ -189,28 +211,18 @@ class JaxExecutor(BatchExecutor):
                        for leaf in jtu.tree_leaves(abstract)))
 
     # -- bookkeeping -----------------------------------------------------------
-    @staticmethod
-    def _jit_compiles(fn, fallback: int) -> int:
-        try:
-            return fn._cache_size()
-        except AttributeError:   # older jax: fall back to shape bookkeeping
-            return fallback
-
     def compile_stats(self) -> Dict[str, int]:
         """Call and (re)compile counters proving steady-state serving does
         zero tracing work (docs/caching.md §Steady-state serving)."""
         with self._lock:
             calls = dict(self._calls)
-            n_shapes = len(self._prefill_shapes)
         return {
             "prefill_calls": calls["prefill"],
             "decode_steps": calls["decode"],
             "insert_calls": calls["insert"],
-            "prefill_compiles": self._jit_compiles(self._prefill, n_shapes),
-            "decode_compiles": self._jit_compiles(self._decode,
-                                                  min(1, calls["decode"])),
-            "insert_compiles": self._jit_compiles(self._insert,
-                                                  min(1, calls["insert"])),
+            "prefill_compiles": self._prefill._cache_size(),
+            "decode_compiles": self._decode._cache_size(),
+            "insert_compiles": self._insert._cache_size(),
         }
 
 
@@ -318,4 +330,5 @@ class StubExecutor(BatchExecutor):
                     "prefill_compiles": 0, "decode_compiles": 0}
 
 
-__all__ = ["BatchExecutor", "JaxExecutor", "StubExecutor"]
+__all__ = ["BatchExecutor", "JaxExecutor", "StubExecutor",
+           "step_functions"]

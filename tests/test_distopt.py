@@ -47,14 +47,13 @@ def test_compression_ratio():
 
 def test_hierarchical_psum_matches_flat():
     """On a 1x1 (pod-less) host mesh the wrapper reduces over 'data'."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",))
     x = jnp.arange(8.0)
 
-    f = shard_map(lambda t: hierarchical_psum(t, mesh), mesh=mesh,
-                  in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+    f = jax.shard_map(lambda t: hierarchical_psum(t, mesh), mesh=mesh,
+                      in_specs=P("data"), out_specs=P("data"),
+                      check_vma=False)
     out = f(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 

@@ -453,3 +453,43 @@ def test_scheduler_arg_validated():
     with pytest.raises(InvalidArgError):
         ServingEngine(None, None, None, batch_slots=2, max_seq=16,
                       executor=StubExecutor(4, 16))   # shape mismatch
+
+
+# --------------------------------------------------------------------------
+# the serve driver surfaces failures
+# --------------------------------------------------------------------------
+
+class _FaultyPrefill(StubExecutor):
+    """A stub whose prefill fails for prompts starting with token 7."""
+
+    def prefill(self, prompt, slot):
+        if int(prompt[0]) == 7:
+            raise DeviceLostError("injected prefill fault")
+        return super().prefill(prompt, slot)
+
+
+def test_serve_driver_raises_when_a_request_fails(capsys):
+    from repro.launch.serve import RequestsFailed, serve
+    ex = _FaultyPrefill(batch_slots=2, max_seq=64)
+    eng = ServingEngine(None, None, None, batch_slots=2, max_seq=64,
+                        executor=ex)
+    reqs = [Request(prompt=np.array([k, 1, 2], np.int32), max_new_tokens=3)
+            for k in (5, 7, 9)]
+    with pytest.raises(RequestsFailed) as ei:
+        serve(eng, reqs)
+    assert [int(r.prompt[0]) for r in ei.value.failed] == [7]
+    assert isinstance(ei.value.failed[0].error, DeviceLostError)
+    assert isinstance(ei.value, ReproError)
+    assert "FAILED DeviceLostError" in capsys.readouterr().out
+    for r in (reqs[0], reqs[2]):
+        assert r.done and r.out_tokens == expect(r)
+
+
+def test_serve_driver_returns_retired_requests():
+    from repro.launch.serve import serve
+    eng, ex = stub_engine(slots=2)
+    rng = np.random.default_rng(11)
+    reqs = [req(rng, max_new=3) for _ in range(4)]
+    done = serve(eng, reqs, arrival_every=2)
+    assert sorted(r.id for r in done) == sorted(r.id for r in reqs)
+    assert all(r.done and r.out_tokens == expect(r) for r in reqs)
