@@ -28,6 +28,7 @@ instance to use a private cache (each runtime ``Device`` owns one).
 
 from __future__ import annotations
 
+import re
 import threading
 import warnings
 from typing import Callable, Dict, Optional, Sequence, Union
@@ -103,6 +104,10 @@ class CompiledKernel:
                 def launch(bufs, scals):
                     return self.prog.run_ndrange(bufs, scals, gsz,
                                                  group_range=grange)
+                # the device program is named jit_dsl_<kernel> in a
+                # profile's XLA Modules, not jit_launch for every kernel
+                launch.__name__ = launch.__qualname__ = \
+                    "dsl_" + re.sub(r"\W", "_", self.name)
                 fn = jax.jit(launch)
                 self._jit_cache[key] = fn
         return fn(buffers, {k: np.asarray(v) for k, v in scalars.items()})

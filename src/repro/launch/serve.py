@@ -26,6 +26,7 @@ from repro.core.errors import ReproError
 from repro.distributed.sharding import BASELINE_RULES
 from repro.models import init_params
 from repro.runtime import Context
+from repro.runtime.trace import spans
 from repro.serving import Request, ServingEngine
 
 
@@ -51,8 +52,10 @@ def serve(eng: ServingEngine, reqs: List[Request], arrival_every: int = 1,
     pending = list(reqs)
     # staggered arrivals, then pump the scheduler until the queue drains —
     # optionally recording every DAG command (plus a kv_pages_live
-    # counter track) as a Chrome trace
+    # counter track and the engine's and executor's spans) as a Chrome
+    # trace
     ctx = eng.context
+    t0_ns = time.monotonic_ns()
     with ctx.trace() as tr:
         while pending or eng.scheduler_stats["waiting"] or \
                 eng.scheduler_stats["running"]:
@@ -64,6 +67,7 @@ def serve(eng: ServingEngine, reqs: List[Request], arrival_every: int = 1,
                            process="serve")
     dt = time.time() - t0
     if trace:
+        tr.add_spans(s for s in spans() if s.start_ns >= t0_ns)
         doc = tr.export(trace)
         print(f"trace: {len(doc['traceEvents'])} events -> {trace} "
               f"(load in chrome://tracing)")
@@ -113,8 +117,9 @@ def main(argv=None):
     ap.add_argument("--arrival-every", type=int, default=1,
                     help="submit one request every N scheduler steps")
     ap.add_argument("--trace", metavar="OUT.JSON", default=None,
-                    help="export the run's event DAG as Chrome-trace "
-                         "JSON (open in chrome://tracing, docs/mesh.md)")
+                    help="export the run's event DAG and program spans "
+                         "as Chrome-trace JSON (open in chrome://tracing, "
+                         "docs/mesh.md)")
     args = ap.parse_args(argv)
 
     cfg = configs.get_smoke(args.arch) if args.smoke \

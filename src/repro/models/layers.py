@@ -9,6 +9,7 @@ layer, mirroring pocl's device-specific builtin libraries.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -23,10 +24,25 @@ from .config import ModelConfig
 Params = Dict[str, jnp.ndarray]
 
 
+def scoped(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``, so
+    its ops carry the name in their metadata and a profile's ``tf_op``
+    (``.../while/body/attention/...``).  Trace-time only: the compiled
+    program runs the same."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
 # ---------------------------------------------------------------------------
 # primitives
 # ---------------------------------------------------------------------------
 
+@scoped("norm")
 def norm(x, p: Params, cfg: ModelConfig, eps: float = 1e-6):
     if "b" in p:                                   # layernorm
         xf = x.astype(jnp.float32)
@@ -65,6 +81,7 @@ from .flash import blocked_attention  # noqa: E402  (memory-efficient custom-VJP
 # attention block
 # ---------------------------------------------------------------------------
 
+@scoped("attention")
 def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
               positions, causal: bool = True, kv_x=None,
               use_rope: bool = True,
@@ -102,12 +119,13 @@ def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
             row_update = jax.vmap(
                 lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
                     c, n, i, axis=1))
-            k_cache = row_update(
-                k_cache, k.transpose(0, 2, 1, 3).astype(k_cache.dtype),
-                row_idx)
-            v_cache = row_update(
-                v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype),
-                row_idx)
+            with jax.named_scope("cache_update"):
+                k_cache = row_update(
+                    k_cache, k.transpose(0, 2, 1, 3).astype(k_cache.dtype),
+                    row_idx)
+                v_cache = row_update(
+                    v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype),
+                    row_idx)
             kq = jnp.squeeze(q, axis=1)              # (B,H,D)
             o = ops.decode_attention(kq, k_cache, v_cache,
                                      lengths + 1, use_pallas=cfg.use_pallas)
@@ -119,12 +137,13 @@ def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
             out = blocked_attention(q, k, v, causal=True,
                                     block_q=cfg.attn_block_q,
                                     block_k=cfg.attn_block_k)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.transpose(0, 2, 1, 3).astype(k_cache.dtype),
-                0, axis=2)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype),
-                0, axis=2)
+            with jax.named_scope("cache_update"):
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    k_cache, k.transpose(0, 2, 1, 3).astype(k_cache.dtype),
+                    0, axis=2)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype),
+                    0, axis=2)
             new_cache = (k_cache, v_cache, lengths + S)
     else:
         if cfg.use_pallas and S <= 4096 and kv_x is None:
@@ -144,6 +163,7 @@ def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
 # FFN: dense MLP and MoE
 # ---------------------------------------------------------------------------
 
+@scoped("mlp")
 def mlp(x, p: Params, cfg: ModelConfig, rules: ShardingRules):
     h = jnp.einsum("bsd,df->bsf", x, p["w_up"])
     if "w_gate" in p:

@@ -61,6 +61,7 @@ def _cast(params: Params, cfg: ModelConfig):
 # embedding / head
 # ---------------------------------------------------------------------------
 
+@layers.scoped("embed")
 def embed_tokens(params: Params, tokens, cfg: ModelConfig,
                  rules: ShardingRules):
     # T5-style sqrt(d) embedding scale: brings the residual stream to
@@ -71,6 +72,7 @@ def embed_tokens(params: Params, tokens, cfg: ModelConfig,
     return constrain(x, rules, "batch", "act_seq", "d_model")
 
 
+@layers.scoped("lm_head")
 def lm_head(params: Params, x, cfg: ModelConfig, rules: ShardingRules):
     x = layers.norm(x, params["ln_f"], cfg)
     w = params["unembed"] if "unembed" in params else params["embed"].T

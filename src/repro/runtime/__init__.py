@@ -10,7 +10,8 @@ Layering (docs/runtime.md, docs/memory.md, docs/host_api.md):
   platform.py   — Platform / Device / Buffer (clGetPlatformIDs et al.)
   bufalloc.py   — the pocl buffer allocator + span-granular residency
   memory.py     — sub-buffers, zero-copy map/unmap, size-class pooling
-  trace.py      — ChromeTrace: event-DAG export for chrome://tracing
+  trace.py      — ChromeTrace: event-DAG export for chrome://tracing;
+                  span(): program spans for the profiler and the export
 """
 
 from ..core.errors import (BuildError, InvalidArgError, InvalidBufferError,

@@ -46,7 +46,7 @@ from .platform import (Buffer, Device, Platform, create_buffer,
                        default_platform)
 from .queue import CommandQueue
 from .scheduler import CoExecutor
-from .trace import ChromeTrace
+from .trace import ChromeTrace, record_spans
 
 __all__ = [
     "Context", "default_context",
@@ -201,7 +201,9 @@ class Context:
         Existing queues and queues created inside the window are both
         attached; on exit collection stops but the recorded events stay
         on ``tr`` for export.  Pass a :class:`ChromeTrace` to accumulate
-        several windows into one file."""
+        several windows into one file.  Program spans
+        (:func:`~repro.runtime.trace.span`) record while the window is
+        open; :meth:`ChromeTrace.add_spans` puts them in the file."""
         tr = tr or ChromeTrace()
         with self._lock:
             self._trace = tr
@@ -209,7 +211,8 @@ class Context:
         for q in queues:
             tr.attach_queue(q)
         try:
-            yield tr
+            with record_spans():
+                yield tr
         finally:
             with self._lock:
                 self._trace = None

@@ -71,6 +71,7 @@ from .events import (CommandError, DependencyError, Event, EventStatus,
 from .memory import (MAP_READ_WRITE, MAP_WRITE_INVALIDATE, MapError,
                      MappedRegion, _flat_view)
 from .platform import Buffer, Device
+from .trace import current_span, span
 
 #: queue fusion modes: "off" never rewrites, "flush" rewrites the window
 #: at flush()/finish() time, "eager" additionally pre-stitches the
@@ -88,10 +89,11 @@ class _Command:
     """One node of the DAG: a host thunk plus its event and wait list."""
 
     __slots__ = ("fn", "event", "deps", "remaining", "submitted",
-                 "failed_dep", "meta")
+                 "failed_dep", "meta", "parent")
 
     def __init__(self, fn: Callable[[], None], event: Event,
-                 deps: Sequence[Event], meta=None):
+                 deps: Sequence[Event], meta=None,
+                 parent: Optional[int] = None):
         self.fn = fn
         self.event = event
         self.deps: List[Event] = list(deps)
@@ -102,6 +104,8 @@ class _Command:
         # _KernelLaunch (fusible), a _BufferUse (transfer/map — names the
         # buffers it touches), or None (opaque: native/deprecated paths)
         self.meta = meta
+        # the span that enqueued it (repro.runtime.trace), while tracing
+        self.parent = parent
 
 
 class _KernelLaunch:
@@ -257,7 +261,7 @@ class CommandQueue:
                     self._ooo_barrier = None
                 else:
                     deps.append(self._ooo_barrier)
-            cmd = _Command(fn, ev, deps, meta=meta)
+            cmd = _Command(fn, ev, deps, meta=meta, parent=current_span())
             self._pending.append(cmd)
             self._last_event = ev
             self._issued.append(ev)
@@ -730,7 +734,8 @@ class CommandQueue:
         # deps are a subset of the head's, so the head's list is the
         # fused node's full wait list (and can never reach back into the
         # chain — no cycles through mirrored completions)
-        fused_cmd = _Command(run, fev, chain[0].deps)
+        fused_cmd = _Command(run, fev, chain[0].deps,
+                             parent=chain[0].parent)
         sink = self.trace_sink
         if sink is not None:
             sink.on_command(fev, fused_cmd.deps, self)
@@ -872,13 +877,17 @@ class CommandQueue:
                 f"command {cmd.event.name!r} abandoned: dependency "
                 f"{cmd.failed_dep.name!r} failed"))
             return
-        cmd.event._transition(EventStatus.RUNNING)
+        ev = cmd.event
+        ev._transition(EventStatus.RUNNING)
         try:
-            cmd.fn()
+            with span("queue.command", parent=cmd.parent, command=ev.name,
+                      kind=ev.kind,
+                      wait_us=(ev.start_ns - ev.submit_ns) / 1e3):
+                cmd.fn()
         except BaseException as e:  # noqa: BLE001 - must reach waiters
-            cmd.event.fail(e)
+            ev.fail(e)
         else:
-            cmd.event.complete()
+            ev.complete()
 
     def cancel_pending(self, error: Optional[BaseException] = None
                        ) -> List[Event]:
@@ -918,6 +927,10 @@ class CommandQueue:
         ``RuntimeError`` if ``timeout`` (seconds) expires — e.g. a wait
         list references an event of a queue that was never flushed, or an
         incomplete :class:`~repro.runtime.events.UserEvent`."""
+        with span("queue.finish"):
+            self._finish(timeout)
+
+    def _finish(self, timeout: Optional[float]) -> None:
         self.flush()
         with self._lock:
             issued = list(self._issued)

@@ -32,21 +32,200 @@ bench_mesh CI gate, and the docs-job check.
 Entry points: ``Context.trace()`` wraps a host-API region
 (docs/host_api.md), ``ServingMesh.attach_trace`` wires a whole replica
 mesh, and ``launch/serve.py --trace out.json`` records a serving run.
+
+**Program spans** (docs/runtime.md §Observability): :func:`span` times
+a stretch of host work inside the program (the serving engine's step and
+request lifecycle, the executor's dispatch and read-back, the queue's
+commands) into two sinks: a ``jax.profiler.TraceAnnotation``, so the
+span lands on the host plane of a profiler trace on the device
+timeline's clock, and a bounded in-memory record on
+``time.monotonic_ns`` (the clock of the
+:class:`~repro.runtime.events.Event` counters), read with :func:`spans`.
+Spans record only while tracing is on: while a JAX profiler session is
+active, or while a ``Context.trace()`` window is open.  Otherwise a span
+costs one check and builds nothing.  :meth:`ChromeTrace.add_spans` puts
+recorded spans beside the DAG slices.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple,
+                    Optional, Sequence, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 from .events import Event
 
-__all__ = ["ChromeTrace", "validate_trace"]
+__all__ = ["ChromeTrace", "Span", "clear_spans", "current_span",
+           "record_spans", "span", "spans", "validate_trace"]
 
 _flow_ids = itertools.count(1)
+
+
+# ---------------------------------------------------------------------------
+# program spans
+# ---------------------------------------------------------------------------
+
+#: spans the in-memory record keeps; the oldest fall out beyond it
+SPAN_CAPACITY = 1 << 16
+
+
+class Span(NamedTuple):
+    """One recorded span.  ``start_ns``/``end_ns`` are
+    ``time.monotonic_ns()``; ``parent`` is the ``id`` of the span it ran
+    under (``None`` at the top); ``thread`` names the thread that opened
+    it; ``args`` holds its counts (request id, rows, tokens, ...)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    id: int
+    parent: Optional[int]
+    thread: str
+    args: Dict[str, Any]
+
+
+_record: deque = deque(maxlen=SPAN_CAPACITY)
+_record_lock = threading.Lock()
+_span_ids = itertools.count(1)
+_tls = threading.local()
+_windows = 0                   # open record_spans() windows
+_windows_lock = threading.Lock()
+_profiling = TraceAnnotation.is_enabled
+
+
+@contextlib.contextmanager
+def record_spans() -> Iterator[None]:
+    """Record spans inside this window, with or without a profiler
+    session (``Context.trace()`` opens one)."""
+    global _windows
+    with _windows_lock:
+        _windows += 1
+    try:
+        yield
+    finally:
+        with _windows_lock:
+            _windows -= 1
+
+
+def _stack() -> List[int]:
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack
+
+
+def current_span() -> Optional[int]:
+    """The ``id`` of the innermost ``with span(...)`` open on this
+    thread, or ``None``: what a command records as the span that
+    enqueued it."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
+
+
+class _Span:
+    """A span being recorded.  Opened by :func:`span`; ``with`` makes it
+    the parent of spans opened inside it on this thread, and closes it.
+    Held without ``with``, it crosses calls (a request's lifecycle) and
+    :meth:`end` closes it."""
+
+    __slots__ = ("name", "args", "id", "parent", "thread", "start_ns",
+                 "_annotation")
+
+    def __init__(self, name: str, parent: Optional[int],
+                 args: Dict[str, Any]):
+        self.name, self.args = name, args
+        self.id = next(_span_ids)
+        self.parent = parent if parent is not None else current_span()
+        self.thread = threading.current_thread().name
+        self._annotation = None
+        if _profiling():
+            self._annotation = TraceAnnotation(name, **args)
+            self._annotation.__enter__()
+        self.start_ns = time.monotonic_ns()
+
+    def set(self, **args) -> None:
+        """Add counts known only once the work has run."""
+        self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
+
+    def end(self, **args) -> None:
+        if args:
+            self.set(**args)
+        end_ns = time.monotonic_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        rec = Span(self.name, self.start_ns, end_ns, self.id, self.parent,
+                   self.thread, self.args)
+        with _record_lock:
+            _record.append(rec)
+
+    def __enter__(self) -> "_Span":
+        _stack().append(self.id)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _stack().pop()
+        self.end()
+
+
+class _Off:
+    """What :func:`span` returns while tracing is off: does nothing."""
+
+    __slots__ = ()
+    id = None
+
+    def set(self, **args) -> None:
+        pass
+
+    def end(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+def span(name: str, parent: Optional[int] = None, **args):
+    """A span of host work named ``name`` with counts ``args``::
+
+        with span("executor.decode", rows=n) as s:
+            ...
+            s.set(bucket=b)          # a count known only at the end
+
+    Its parent is ``parent`` (a span ``id``, for work handed to another
+    thread) or else the innermost ``with span`` open on this thread.
+    Spans record while a JAX profiler session is active or a
+    :func:`record_spans` window (``Context.trace()``) is open.  Otherwise
+    this is one check and returns a shared do-nothing span whose ``id``
+    is ``None``."""
+    if not (_windows or _profiling()):
+        return _OFF
+    return _Span(name, parent, args)
+
+
+def spans() -> Tuple[Span, ...]:
+    """A snapshot of the record, oldest first (at most
+    :data:`SPAN_CAPACITY` spans)."""
+    with _record_lock:
+        return tuple(_record)
+
+
+def clear_spans() -> None:
+    with _record_lock:
+        _record.clear()
 
 
 class ChromeTrace:
@@ -166,6 +345,29 @@ class ChromeTrace:
             self._extra.append({"ph": "C", "name": name, "pid": pid,
                                 "tid": 0, "_ts_ns": ts,
                                 "args": {"value": value}})
+
+    def add_spans(self, recorded: Iterable[Span],
+                  process: str = "host") -> int:
+        """Add recorded program spans (:func:`spans`) as ``ph:"X"``
+        slices, one thread row per host thread under ``process``.
+        ``queue.command`` spans are left out: the queue's DAG slices
+        already show each command.  Returns the number added."""
+        added = 0
+        with self._lock:
+            pid = self._pid(process)
+            for s in recorded:
+                if s.name == "queue.command":
+                    continue
+                args = dict(s.args, span=s.id)
+                if s.parent is not None:
+                    args["parent"] = s.parent
+                self._extra.append({
+                    "ph": "X", "name": s.name, "cat": "span", "pid": pid,
+                    "tid": self._tid(pid, s.thread), "_ts_ns": s.start_ns,
+                    "dur": max(0, s.end_ns - s.start_ns) / 1e3,
+                    "args": args})
+                added += 1
+        return added
 
     # -- export ---------------------------------------------------------------
     def trace_events(self) -> List[dict]:

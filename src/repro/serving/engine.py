@@ -65,6 +65,7 @@ from repro.runtime.bufalloc import OutOfMemory
 from repro.runtime.events import CommandError
 from repro.runtime.memory import BufferPool
 from repro.runtime.queue import CommandQueue
+from repro.runtime.trace import span
 
 from .executor import BatchExecutor
 
@@ -89,7 +90,8 @@ class Request:
     :class:`~repro.core.errors.ReproError` on failure.  ``priority``
     orders preemption victims (lower preempts first); ``eos_token``
     stops generation early.  ``id``/``submit_step``/``finish_step``/
-    ``preemptions`` are scheduler bookkeeping filled in by the engine.
+    ``preemptions`` are scheduler bookkeeping filled in by the engine;
+    ``submit_ns`` is the ``time.monotonic_ns()`` of its last submit.
     """
 
     prompt: np.ndarray                   # (S,) int32
@@ -104,6 +106,7 @@ class Request:
     submit_step: int = -1
     finish_step: int = -1
     preemptions: int = 0
+    submit_ns: int = -1
 
 
 class _Slot:
@@ -240,6 +243,9 @@ class ServingEngine:
         self._dag_accum = {"steps": 0, "events": 0, "prefill_events": 0,
                            "decode_events": 0, "wall_s": 0.0,
                            "busy_s": 0.0}
+        # request id -> its open lifecycle span, while tracing
+        # (engine.queued, then engine.first_token)
+        self._open_spans: Dict[int, Any] = {}
 
     # ======================================================================
     # introspection
@@ -324,8 +330,10 @@ class ServingEngine:
         request.done = False
         request.error = None
         request.submit_step = self._step_idx
+        request.submit_ns = time.monotonic_ns()
         request.finish_step = -1
         self._sched["submitted"] += 1
+        self._lifecycle(request, "engine.queued")
         if front:
             self._waiting.appendleft(request)
         else:
@@ -386,7 +394,23 @@ class ServingEngine:
         and carry no error; re-``submit`` re-initializes them."""
         out = list(self._waiting)
         self._waiting.clear()
+        for r in out:
+            self._lifecycle(r)
         return out
+
+    def _lifecycle(self, req: Request, name: Optional[str] = None) -> None:
+        """Close the request's open lifecycle span and, with ``name``,
+        open the next one (docs/runtime.md §Observability):
+        ``engine.queued`` from submit to admission into a slot,
+        ``engine.first_token`` from admission to token 0.  Opens nothing
+        while tracing is off."""
+        s = self._open_spans.pop(req.id, None)
+        if s is not None:
+            s.end()
+        if name is not None:
+            s = span(name, request=req.id)
+            if s.id is not None:
+                self._open_spans[req.id] = s
 
     # ======================================================================
     # KV paging
@@ -441,6 +465,7 @@ class ServingEngine:
         victim.out_tokens = []
         victim.preemptions += 1
         self._waiting.appendleft(victim)
+        self._lifecycle(victim, "engine.queued")
         self._sched["preemptions"] += 1
         return vi
 
@@ -483,6 +508,7 @@ class ServingEngine:
         r.out_tokens = []
         r.preemptions += 1
         self._waiting.appendleft(r)
+        self._lifecycle(r, "engine.queued")
         self._sched["preemptions"] += 1
 
     # ======================================================================
@@ -493,6 +519,7 @@ class ServingEngine:
         req.done = True
         req.finish_step = self._step_idx
         self._sched["completed"] += 1
+        self._lifecycle(req)
 
     def _evict(self, i: int) -> Request:
         """Free slot ``i``'s pages and mark its request finished."""
@@ -515,6 +542,7 @@ class ServingEngine:
         req.finish_step = self._step_idx
         self._sched["failed"] += 1
         self._faults.pop(req.id, None)
+        self._lifecycle(req)
         return req
 
     def _should_finish(self, slot: _Slot) -> bool:
@@ -545,6 +573,7 @@ class ServingEngine:
             return None
         self._slots[i] = slot
         req.state = RequestState.RUNNING
+        self._lifecycle(req, "engine.first_token")
         return slot
 
     def _refill_slots(self, finished: List[Request]) -> List[tuple]:
@@ -612,6 +641,7 @@ class ServingEngine:
         slot.inserted = True
         tok = int(holder["tok"])
         req.out_tokens.append(tok)
+        self._lifecycle(req)
         slot.last_tok = tok
         if self._should_finish(slot):
             finished.append(self._evict(i))
@@ -622,6 +652,11 @@ class ServingEngine:
         command for the already-resident slots, all independent nodes on
         the out-of-order queue, then failure surfacing and state
         updates."""
+        with span("engine.round", prefills=len(staged)) as rnd:
+            self._round(staged, events, finished, rnd)
+
+    def _round(self, staged: List[tuple], events: List,
+               finished: List[Request], rnd) -> None:
         q = self._queue
         prefills = []
         for i, req in staged:
@@ -636,6 +671,7 @@ class ServingEngine:
                        if self._slots[i] is not None
                        and self._slots[i].inserted
                        and i not in staged_idx]
+        rnd.set(rows=len(decode_rows))
         decode_ev = None
         decode_holder: Dict[str, Any] = {}
         if decode_rows:
@@ -732,6 +768,12 @@ class ServingEngine:
         the step returns."""
         if self.device_lost is not None:
             return []          # terminal: the mesh routes around us
+        with span("engine.step", step=self._step_idx + 1,
+                  waiting=len(self._waiting),
+                  running=self.B - self._slots.count(None)):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         self._step_idx += 1
         self._sched["steps"] += 1
         t0 = time.perf_counter()

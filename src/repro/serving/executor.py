@@ -33,6 +33,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime.trace import span
+
 
 class BatchExecutor:
     """Interface contract (see module docstring).  Subclasses must set
@@ -138,6 +140,13 @@ class JaxExecutor(BatchExecutor):
 
     ``device`` (a JAX device, default ``jax.devices()[0]``) holds the
     params and every cache, so every step runs there.
+
+    While tracing (docs/runtime.md §Observability), ``executor.prefill``
+    (``tokens``, ``bucket``) and ``executor.decode`` (``rows``) each span
+    two children: ``executor.dispatch``, the host copies of the inputs and
+    the jitted call returning, and ``executor.readback``, the blocking
+    copy of the sampled token(s) to the host.  ``executor.insert`` spans
+    the insert's dispatch.
     """
 
     def __init__(self, cfg, params, rules, batch_slots: int, max_seq: int,
@@ -182,27 +191,38 @@ class JaxExecutor(BatchExecutor):
     def prefill(self, prompt: np.ndarray, slot: int):
         plen = int(len(prompt))
         padded = self.bucket(plen)
-        toks = np.zeros((1, padded), np.int32)
-        toks[0, :plen] = prompt
         with self._lock:
             self._calls["prefill"] += 1
-        tok, frag = self._prefill(self.params, self._put(toks),
-                                  self._caches(1), np.int32(plen - 1),
-                                  np.int32(plen), np.int32(slot))
-        return frag, int(tok)
+        with span("executor.prefill", tokens=plen, bucket=padded):
+            with span("executor.dispatch"):
+                toks = np.zeros((1, padded), np.int32)
+                toks[0, :plen] = prompt
+                tok, frag = self._prefill(
+                    self.params, self._put(toks), self._caches(1),
+                    np.int32(plen - 1), np.int32(plen), np.int32(slot))
+            with span("executor.readback"):
+                tok = int(tok)
+        return frag, tok
 
     def insert(self, state, fragment, slot: int):
         with self._lock:
             self._calls["insert"] += 1
-        return self._insert(state, fragment, np.int32(slot))
+        with span("executor.insert"):
+            return self._insert(state, fragment, np.int32(slot))
 
     def decode(self, state, tokens: np.ndarray, occupied: np.ndarray):
         with self._lock:
             self._calls["decode"] += 1
-        tok, state = self._decode(
-            self.params, self._put(np.asarray(tokens, np.int32)[:, None]),
-            state, self._put(np.asarray(occupied)))
-        return state, np.asarray(tok)
+        occupied = np.asarray(occupied)
+        with span("executor.decode", rows=int(np.count_nonzero(occupied))):
+            with span("executor.dispatch"):
+                tok, state = self._decode(
+                    self.params,
+                    self._put(np.asarray(tokens, np.int32)[:, None]),
+                    state, self._put(occupied))
+            with span("executor.readback"):
+                tok = np.asarray(tok)
+        return state, tok
 
     def cache_bytes(self, batch: int, seq: int) -> int:
         import jax.tree_util as jtu
