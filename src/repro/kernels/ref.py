@@ -73,6 +73,35 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return o.reshape(B, H, D)
 
 
+def layer_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
+                           v_cache: jnp.ndarray, lengths: jnp.ndarray,
+                           layer) -> jnp.ndarray:
+    """:func:`decode_attention` over layer ``layer`` of stacked caches
+    in the decode step's layout, (L, B, Hkv, D, S)."""
+    def view(c):
+        return jnp.swapaxes(
+            jax.lax.dynamic_index_in_dim(c, layer, keepdims=False), -1, -2)
+    return decode_attention(q, view(k_cache), view(v_cache), lengths)
+
+
+def cache_write(k_cache: jnp.ndarray, v_cache: jnp.ndarray, k: jnp.ndarray,
+                v: jnp.ndarray, lengths: jnp.ndarray, layer
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Write row ``b``'s new token ``k[b]``, ``v[b]`` ((B, Hkv, D)) at
+    position ``min(lengths[b], S - 1)`` of layer ``layer`` of stacked
+    caches (L, B, Hkv, D, S); nothing else changes.  A select over the
+    positions, so a sharded position axis stays sharded."""
+    B, S = k_cache.shape[1], k_cache.shape[-1]
+    pos = jnp.minimum(jnp.broadcast_to(lengths, (B,)), S - 1)
+    hit = jnp.arange(S)[None, None, None, :] == pos[:, None, None, None]
+
+    def put(cache, new):
+        old = jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+        upd = jnp.where(hit, new[..., None].astype(cache.dtype), old)
+        return jax.lax.dynamic_update_index_in_dim(cache, upd, layer, 0)
+    return put(k_cache, k), put(v_cache, v)
+
+
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     dt = x.dtype
     xf = x.astype(jnp.float32)

@@ -86,8 +86,14 @@ def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
               positions, causal: bool = True, kv_x=None,
               use_rope: bool = True,
               cache: Optional[Tuple] = None):
-    """Self- or cross-attention.  cache=(k_cache, v_cache, lengths) with
-    layout (B, S_cache, KV, D); returns (out, new_cache)."""
+    """Self- or cross-attention; returns (out, new_cache).
+
+    ``cache=(k, v, lengths, layer)``: the whole model's stacked caches in
+    the layout (L, B, KV, D, S) of :func:`repro.models.init_caches`, of
+    which this call reads and writes layer ``layer`` where it lies;
+    ``new_cache`` is the updated ``(k, v)``.  ``cache=(k, v, lengths)``:
+    one layer's caches in the layout (B, KV, S, D) (the vlm and hybrid
+    families); ``new_cache`` is ``(k, v, lengths + S)``."""
     B, S, _ = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q = constrain(q, rules, "batch", "seq", "heads", "head_dim")
@@ -103,7 +109,37 @@ def attention(x, p: Params, cfg: ModelConfig, rules: ShardingRules, *,
             k = rope(k, positions, cfg.rope_theta)
 
     new_cache = None
-    if cache is not None and kv_x is None:
+    if cache is not None and kv_x is None and len(cache) == 4:
+        k_cache, v_cache, lengths, layer = cache
+        if S == 1:
+            # decode: one call writes every row's token at the row's own
+            # length (continuous-batching slots sit at different
+            # positions), in place in the stack; attention then reads the
+            # layer where it lies.  Neither copies a layer out.
+            sharded = rules.cache_seq is not None
+            with jax.named_scope("cache_update"):
+                k_cache, v_cache = ops.cache_write(
+                    k_cache, v_cache, k[:, 0], v[:, 0], lengths, layer,
+                    positions_sharded=sharded)
+            out = ops.layer_decode_attention(
+                q[:, 0], k_cache, v_cache, lengths + 1, layer,
+                positions_sharded=sharded)[:, None]           # (B,1,H,D)
+        else:
+            # prefill: attend causally over fresh K/V, then write the
+            # whole prompt into the layer at position 0
+            out = blocked_attention(q, k, v, causal=True,
+                                    block_q=cfg.attn_block_q,
+                                    block_k=cfg.attn_block_k)
+            start = (layer, 0, 0, 0, 0)
+            with jax.named_scope("cache_update"):
+                k_cache = jax.lax.dynamic_update_slice(
+                    k_cache, k.transpose(0, 2, 3, 1)[None]
+                    .astype(k_cache.dtype), start)
+                v_cache = jax.lax.dynamic_update_slice(
+                    v_cache, v.transpose(0, 2, 3, 1)[None]
+                    .astype(v_cache.dtype), start)
+        new_cache = (k_cache, v_cache)
+    elif cache is not None and kv_x is None:
         k_cache, v_cache, lengths = cache
         if S == 1:
             # decode: append one token then attend over the cache.

@@ -93,32 +93,41 @@ def lm_head(params: Params, x, cfg: ModelConfig, rules: ShardingRules):
 # backbones (mode: "train" | "prefill" | "decode")
 # ---------------------------------------------------------------------------
 
-def _dense_backbone(params, x, cfg, rules, *, positions, caches, mode):
-    use_rope = cfg.family != "encdec"
-
+def _scan_cached(block, x, layer_params, caches):
+    """Run ``block(x, lp, cache) -> (x, aux, (k, v))`` over the layers with
+    ``cache = (k, v, len, layer)``.  The stacked K and V are loop carries,
+    never the scan's xs or ys: each layer reads and writes its part where
+    it lies, and the donated cache is the same buffer from the step's
+    entry to its exit."""
     def body(carry, inp):
+        x, aux, k, v = carry
+        lp, i = inp
+        x, a, (k, v) = block(x, lp, (k, v, caches["len"], i))
+        return (x, aux + a, k, v), None
+
+    layer_ids = jnp.arange(caches["k"].shape[0], dtype=jnp.int32)
+    (x, aux, k, v), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), caches["k"], caches["v"]),
+        (layer_params, layer_ids))
+    return x, aux, {"k": k, "v": v, "len": caches["len"] + x.shape[1]}
+
+
+def _dense_backbone(params, x, cfg, rules, *, positions, caches, mode):
+    if caches is not None:
+        return _scan_cached(
+            lambda x, lp, c: layers.attn_block(x, lp, cfg, rules,
+                                               positions=positions, cache=c),
+            x, params["layers"], caches)
+
+    def body(carry, lp):
         x, aux = carry
-        if caches is None:
-            lp = inp
-            x, a, _ = layers.attn_block(x, lp, cfg, rules,
-                                        positions=positions,
-                                        use_rope=use_rope)
-            return (x, aux + a), None
-        lp, (ck, cv) = inp
-        x, a, nc = layers.attn_block(
-            x, lp, cfg, rules, positions=positions, use_rope=use_rope,
-            cache=(ck, cv, caches["len"]))
-        return (x, aux + a), (nc[0], nc[1])
+        x, a, _ = layers.attn_block(x, lp, cfg, rules, positions=positions)
+        return (x, aux + a), None
 
     body = _maybe_remat(body, cfg) if mode == "train" else body
     aux0 = jnp.zeros((), jnp.float32)
-    if caches is None:
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["layers"])
-        return x, aux, None
-    (x, aux), new_kv = jax.lax.scan(
-        body, (x, aux0), (params["layers"], (caches["k"], caches["v"])))
-    new_len = caches["len"] + x.shape[1]
-    return x, aux, {"k": new_kv[0], "v": new_kv[1], "len": new_len}
+    (x, aux), _ = jax.lax.scan(body, (x, aux0), params["layers"])
+    return x, aux, None
 
 
 def _ssm_backbone(params, x, cfg, rules, *, caches, mode):
@@ -257,28 +266,21 @@ def _encode_audio(params, frames, cfg, rules):
 
 def _encdec_backbone(params, x, cfg, rules, *, positions, enc_out, caches,
                      mode):
-    def body(carry, inp):
-        x = carry
-        if caches is None:
-            lp = inp
-            x, _ = layers.encdec_block(x, lp, cfg, rules, enc_out=enc_out,
-                                       positions=positions)
-            return x, None
-        lp, (ck, cv) = inp
-        x, nc = layers.encdec_block(
-            x, lp, cfg, rules, enc_out=enc_out, positions=positions,
-            cache=(ck, cv, caches["len"]))
-        return x, (nc[0], nc[1])
+    def block(x, lp, cache=None):
+        x, nc = layers.encdec_block(x, lp, cfg, rules, enc_out=enc_out,
+                                    positions=positions, cache=cache)
+        return x, jnp.zeros((), jnp.float32), nc
+
+    if caches is not None:
+        x, aux, nc = _scan_cached(block, x, params["layers"], caches)
+        return x, aux, dict(nc, enc_out=enc_out)
+
+    def body(x, lp):
+        return block(x, lp)[0], None
 
     body = _maybe_remat(body, cfg) if mode == "train" else body
-    aux = jnp.zeros((), jnp.float32)
-    if caches is None:
-        x, _ = jax.lax.scan(body, x, params["layers"])
-        return x, aux, None
-    x, new_kv = jax.lax.scan(body, x,
-                             (params["layers"], (caches["k"], caches["v"])))
-    return x, aux, {"k": new_kv[0], "v": new_kv[1],
-                    "enc_out": enc_out, "len": caches["len"] + x.shape[1]}
+    x, _ = jax.lax.scan(body, x, params["layers"])
+    return x, jnp.zeros((), jnp.float32), None
 
 
 # ---------------------------------------------------------------------------
@@ -389,9 +391,12 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
 
     fam = cfg.family
     out: Dict[str, Any] = {"len": mk((batch,), jnp.int32)}
-    # KV caches live in the attention kernel's (B, KV, S, D) layout
+    # self-attention K and V are stacked over the layers with the
+    # positions minor, (L, B, KV, D, S): the layout a TPU gives them
+    # anyway when head_dim is narrower than its 128 lanes, and the one the
+    # decode step's kernels read and write in place (layers.attention)
     if fam in ("dense", "moe", "encdec"):
-        kv = (L, batch, cfg.n_kv, max_seq, cfg.hd)
+        kv = (L, batch, cfg.n_kv, cfg.hd, max_seq)
         out.update(k=mk(kv), v=mk(kv))
         if fam == "encdec":
             out["enc_out"] = mk((batch, cfg.enc_seq, cfg.d_model))
@@ -421,7 +426,7 @@ def cache_logical_axes(cfg: ModelConfig):
     fam = cfg.family
     out = {"len": (None,)}
     if fam in ("dense", "moe", "encdec"):
-        kv = (None, "batch", "kv_heads", "cache_seq", "head_dim")
+        kv = (None, "batch", "kv_heads", "head_dim", "cache_seq")
         out.update(k=kv, v=kv)
         if fam == "encdec":
             out["enc_out"] = ("batch", None, "d_model")

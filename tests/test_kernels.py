@@ -5,7 +5,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import kv_cache, ops, ref
 
 
 def rnd(rng, shape, dtype=jnp.float32, scale=1.0):
@@ -58,6 +58,61 @@ def test_decode_attention_kernel(B, H, KV, D, S, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+#: the stacked cache of the decode step: 3 layers x 8 rows, (L, B, KV, D, S)
+STACK_L, STACK_S = 3, 256
+#: rows 0-4 at the edge lengths; rows 5-7 unoccupied (the decode step pins
+#: an empty row's length to 0)
+ROW_LENGTHS = [0, 1, 127, 128, STACK_S - 1, 0, 0, 0]
+
+
+def _stack(rng, kv_heads, head_dim):
+    shape = (STACK_L, len(ROW_LENGTHS), kv_heads, head_dim, STACK_S)
+    return rnd(rng, shape, jnp.bfloat16), rnd(rng, shape, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("layer", [0, 1, STACK_L - 1])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_cache_write_kernel(layer, head_dim):
+    """Each row's token lands at its own length in the one layer, bit for
+    bit, and no other element of either stack changes."""
+    rng = np.random.default_rng(6)
+    KV, B = 2, len(ROW_LENGTHS)
+    kc, vc = _stack(rng, KV, head_dim)
+    kn = rnd(rng, (B, KV, head_dim), jnp.bfloat16)
+    vn = rnd(rng, (B, KV, head_dim), jnp.bfloat16)
+    lengths = jnp.asarray(ROW_LENGTHS, jnp.int32)
+    got = kv_cache.cache_write(kc, vc, kn, vn, lengths, layer,
+                               interpret=True)
+    oracle = ref.cache_write(kc, vc, kn, vn, lengths, layer)
+    for cache, new, out, want in zip((kc, vc), (kn, vn), got, oracle):
+        expect = np.array(cache)
+        for b, n in enumerate(ROW_LENGTHS):
+            expect[layer, b, :, :, n] = np.asarray(new[b])
+        np.testing.assert_array_equal(np.asarray(out), expect)
+        np.testing.assert_array_equal(np.asarray(want), expect)
+
+
+@pytest.mark.parametrize("layer", [0, 1, STACK_L - 1])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("block_k", [None, 128])
+def test_layer_decode_attention_kernel(layer, head_dim, block_k):
+    """Attention over one layer of the stack, read in place, against the
+    oracle on that layer's D-minor view, (B, KV, S, D).  The decode step
+    attends over each row's length plus its new token."""
+    rng = np.random.default_rng(7)
+    KV, G, B = 2, 3, len(ROW_LENGTHS)
+    kc, vc = _stack(rng, KV, head_dim)
+    q = rnd(rng, (B, KV * G, head_dim), jnp.bfloat16)
+    lengths = jnp.asarray(ROW_LENGTHS, jnp.int32) + 1
+    out = kv_cache.decode_attention(q, kc, vc, lengths, layer,
+                                    block_k=block_k, interpret=True)
+    want = ref.decode_attention(q, jnp.swapaxes(kc[layer], -1, -2),
+                                jnp.swapaxes(vc[layer], -1, -2), lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
 
 
 @pytest.mark.parametrize("rows,d", [(8, 256), (16, 512), (4, 1024)])

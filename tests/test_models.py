@@ -129,3 +129,53 @@ def test_streaming_ce_matches_standard():
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=1e-3)
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "granite-moe-3b-a800m"])
+def test_served_decode_matches_teacher_forced(arch):
+    """Serving's jitted steps (``step_functions``, through JaxExecutor):
+    requests prefilled into separate slots at different times decode
+    through the stacked cache with empty rows beside them, and each served
+    token is the teacher-forced forward's best at its position (within
+    bf16 rounding of the logits).  The moe config gets room for every
+    token at every expert, so the teacher-forced pass drops none where a
+    one-token decode step never does."""
+    from repro.serving.executor import JaxExecutor
+
+    cfg = configs.get_smoke(arch)
+    if cfg.family == "moe":
+        cfg = configs.get_smoke(arch, capacity_factor=float(cfg.n_experts))
+    params = init_params(cfg, jax.random.PRNGKey(4))
+    ex = JaxExecutor(cfg, params, BASELINE_RULES, batch_slots=5,
+                     max_seq=128)
+    rng = np.random.default_rng(4)
+    prompts = {1: rng.integers(0, cfg.vocab, 5),
+               3: rng.integers(0, cfg.vocab, 19)}
+    late = {3: 2}                      # slot 3 joins after two steps
+    state = ex.init_state()
+    served = {slot: [] for slot in prompts}
+    occupied = np.zeros(5, bool)
+    tokens = np.zeros(5, np.int32)
+    for step in range(6):
+        for slot, p in prompts.items():
+            if late.get(slot, 0) == step:
+                frag, tok = ex.prefill(p, slot)
+                state = ex.insert(state, frag, slot)
+                served[slot].append(tok)
+                occupied[slot], tokens[slot] = True, tok
+        state, out = ex.decode(state, tokens, occupied)
+        for slot in prompts:
+            if occupied[slot]:
+                served[slot].append(int(out[slot]))
+                tokens[slot] = out[slot]
+    lens = np.asarray(state["len"])
+    assert lens[[0, 2, 4]].tolist() == [0, 0, 0]
+    for slot, p in prompts.items():
+        toks = served[slot]
+        assert lens[slot] == len(p) + len(toks) - 1
+        full = jnp.asarray(np.concatenate([p, toks[:-1]])[None], jnp.int32)
+        logits, _, _ = forward(params, full, cfg, BASELINE_RULES,
+                               mode="train")
+        rows = np.asarray(logits[0, len(p) - 1:], np.float32)
+        gap = rows.max(-1) - rows[np.arange(len(toks)), toks]
+        assert gap.max() < 0.1, (slot, gap)

@@ -12,7 +12,9 @@ module is imported), so every test worker collects the same tests and
 only the worker given this file loads the TPU library.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.distributed.sharding import BASELINE_RULES
+from repro.kernels import kv_cache
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
@@ -89,11 +92,23 @@ def _model_kernel_case(name, sh):
                  _spec((SLOTS, KV, MAX_SEQ, D), bf16, sh),
                  _spec((SLOTS, KV, MAX_SEQ, D), bf16, sh),
                  _spec((SLOTS,), jnp.int32, sh)))
+    stack = _spec((SMOLLM.n_layers, SLOTS, KV, D, MAX_SEQ), bf16, sh)
+    lengths = _spec((SLOTS,), jnp.int32, sh)
+    if name == "layer_decode_attention":
+        return (lambda q, k, v, n: kv_cache.decode_attention(
+                    q, k, v, n, 7, interpret=False),
+                (_spec((SLOTS, H, D), bf16, sh), stack, stack, lengths))
+    if name == "cache_write":
+        new = _spec((SLOTS, KV, D), bf16, sh)
+        return (lambda k, v, kn, vn, n: kv_cache.cache_write(
+                    k, v, kn, vn, n, 7, interpret=False),
+                (stack, stack, new, new, lengths))
     return (lambda x, w: rmsnorm(x, w, interpret=False),
             (_spec((SLOTS, d), bf16, sh), _spec((d,), jnp.float32, sh)))
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "decode_attention",
+                                  "layer_decode_attention", "cache_write",
                                   "rmsnorm"])
 def test_model_kernel_compiles_to_mosaic(one_chip, name):
     fn, args = _model_kernel_case(name, one_chip)
@@ -101,25 +116,56 @@ def test_model_kernel_compiles_to_mosaic(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_full_width_decode_step_fits_hbm(one_chip):
-    """The smollm-135m decode step at chip_smoke's slots x max_seq: the
-    compiler accepts it and its footprint fits one chip's HBM."""
+#: an HLO instruction that makes a new array: ``%name = dtype[dims]{layout} op(``
+_HLO_ARRAY_OP = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\{[^}]*\} "
+    r"(copy|scatter|dynamic-update-slice)\(")
+
+
+def _array_ops(hlo: str):
+    """(op, bytes, line) of every copy, scatter and dynamic-update-slice
+    of the compiled module."""
+    for line in hlo.splitlines():
+        m = _HLO_ARRAY_OP.match(line)
+        if m:
+            n = math.prod(int(d) for d in m.group(2).split(",") if d)
+            bits = int(re.sub(r"\D", "", m.group(1)) or 8)   # bf16, pred
+            yield m.group(3), n * bits // 8, line
+
+
+@pytest.mark.parametrize("slots,max_seq", [
+    (SLOTS, MAX_SEQ),
+    (64, 2048),                       # the benchmark's chat cell
+])
+def test_full_width_decode_step_fits_hbm(one_chip, slots, max_seq):
+    """The smollm-135m decode step at full width: the compiler accepts it,
+    its footprint fits one chip's HBM, and it keeps the KV cache where it
+    lies: the donated cache aliases the result, the temporaries stay
+    under a tenth of the cache, and no copy, scatter or
+    dynamic-update-slice makes an array as large as one layer's K (the
+    per-step cast of the float32 weights, ``params[...]``, aside)."""
     cfg = SMOLLM
     put = lambda t: jax.tree.map(
         lambda s: _spec(s.shape, s.dtype, one_chip), t)
     params = put(abstract_params(cfg))
-    caches = put(init_caches(cfg, SLOTS, MAX_SEQ, abstract=True))
-    toks = _spec((SLOTS, 1), jnp.int32, one_chip)
-    occupied = _spec((SLOTS,), jnp.bool_, one_chip)
+    caches = put(init_caches(cfg, slots, max_seq, abstract=True))
+    toks = _spec((slots, 1), jnp.int32, one_chip)
+    occupied = _spec((slots,), jnp.bool_, one_chip)
     decode = step_functions(cfg, BASELINE_RULES, {})[2]
-    mem = decode.lower(params, toks, caches, occupied).compile() \
-        .memory_analysis()
+    compiled = decode.lower(params, toks, caches, occupied).compile()
+    mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     cache_bytes = sum(l.size * l.dtype.itemsize
                       for l in jax.tree.leaves(caches))
     assert used >= cache_bytes
     assert used < HBM_BYTES, f"decode step needs {used} B of {HBM_BYTES}"
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes / 10, mem
+    layer_k = caches["k"].size * caches["k"].dtype.itemsize // cfg.n_layers
+    big = [line for op, n, line in _array_ops(compiled.as_text())
+           if n >= layer_k and 'op_name="params[' not in line]
+    assert not big, big
 
 
 @pytest.mark.parametrize("name,shape,params", [
