@@ -103,6 +103,7 @@ def run(ctx):
 
     span = H.span
     counter = H.CompileCounter()
+    gcw = H.GcWatch()
     V, S = int(cfg["vocab_size"]), int(srv["max_seq"])
     params = weights.dense_lm(cfg, ctx.seed, ctx.devices[0])
     eng = ServingEngine(model_config(cfg), params, BASELINE_RULES,
@@ -139,11 +140,12 @@ def run(ctx):
     i, n = 0, len(arrivals)
     timed: List[Rec] = []
     late: List[float] = []
+    longest_turn = 0.0
     errors: List[str] = []
     while True:
         now = time.perf_counter()
         if now >= origin and not counter.armed and "start" not in backlog:
-            counter.armed = True
+            counter.armed = gcw.armed = True
             backlog["start"] = len(queued)
         if ctx.trace:
             if now >= origin and not prof.active and not prof.done:
@@ -179,6 +181,8 @@ def run(ctx):
         with span("bench.step"):
             eng.step()
         t = time.perf_counter()
+        if counter.armed:
+            longest_turn = max(longest_turn, t - now)
         while queued and queued[0].req.state != "waiting":
             running.append(queued.popleft())
         still = []
@@ -195,7 +199,8 @@ def run(ctx):
             else:
                 still.append(r)
         running = still
-    counter.armed = False
+    counter.armed = gcw.armed = False
+    gcw.close()
     if prof.active:
         counters1 = eng.compile_stats
         prof.stop()
@@ -227,6 +232,11 @@ def run(ctx):
     print(f"window: compiles {counter.compiles}, traces {counter.traces}; "
           f"waiting queue {backlog.get('start')} at start, "
           f"{backlog.get('end')} at end; completed {len(completed)}",
+          flush=True)
+    print(f"host: longest turn of the loop {1e3 * longest_turn:.1f} ms; "
+          f"garbage collections of generations 0/1/2: "
+          f"{'/'.join(map(str, gcw.count))}, longest "
+          f"{'/'.join(f'{1e3 * x:.1f}' for x in gcw.longest)} ms",
           flush=True)
     sweep = {
         "rate_rps": rate, "queue_start": backlog.get("start"),

@@ -176,6 +176,33 @@ class CompileCounter:
         return self.counts[self.KEYS[1]]
 
 
+class GcWatch:
+    """The interpreter's garbage collections while :attr:`armed`: how many
+    of each generation, and the longest pause of each (seconds)."""
+
+    def __init__(self):
+        import gc
+        self.armed = False
+        self.count = [0, 0, 0]
+        self.longest = [0.0, 0.0, 0.0]
+        self._t0 = None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self.armed and self._t0 is not None:
+            g = info["generation"]
+            self.count[g] += 1
+            self.longest[g] = max(self.longest[g],
+                                  time.perf_counter() - self._t0)
+
+    def close(self) -> None:
+        import gc
+        if self._on in gc.callbacks:
+            gc.callbacks.remove(self._on)
+
+
 # ---------------------------------------------------------------------------
 # the profiler window
 # ---------------------------------------------------------------------------
@@ -308,7 +335,7 @@ def print_checks(outcome: Outcome) -> None:
     sys.stderr.flush()
 
 
-__all__ = ["Cell", "Check", "CompileCounter", "NoChip", "Outcome",
+__all__ = ["Cell", "Check", "CompileCounter", "GcWatch", "NoChip", "Outcome",
            "Profiler", "SpecError", "device_record", "enable_compile_cache",
            "load_cell", "load_driver", "load_metric", "memory_peak_bytes",
            "percentile", "print_checks", "require_chips", "result_line",

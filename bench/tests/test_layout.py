@@ -46,6 +46,18 @@ def test_configs_and_cells_have_their_files():
         assert (REPO / f"bench/drivers/{doc['driver']}.py").is_file()
 
 
+def test_rate_follows_knee():
+    """A mix that records its knee offers ``rate_of_knee`` x knee, as
+    ``bench/knee_sweep.py --write`` stores it."""
+    mixes = [json.loads(p.read_text())["mix"]
+             for p in sorted((REPO / "bench/workloads").glob("*.json"))]
+    kneed = [m for m in mixes if "knee_rps" in m]
+    assert kneed
+    for mix in kneed:
+        assert mix["rate_rps"] == round(
+            mix["rate_of_knee"] * mix["knee_rps"], 3), mix
+
+
 def test_metrics_declare_what_benchmark_json_says():
     for m in SPEC["end_to_end"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
